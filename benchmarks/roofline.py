@@ -45,7 +45,7 @@ from repro.core import metrics as M
 from repro.core.arena import arena_search
 from repro.core.quant import QuantParams
 from repro.core.router import route_queries
-from repro.kernels.beam_search import beam_impl, beam_search_stats
+from repro.kernels.beam_search import beam_search_stats
 from repro.kernels.merge_topk import merge_topk
 from repro.kernels.quant_distance import quant_scores
 
@@ -144,7 +144,7 @@ def _beam_rows(quick: bool, peaks: Dict[str, float]) -> List[Dict]:
         # "map" is the old sequential CPU special case, measured and
         # reported because its per-shard early termination keeps it
         # competitive on CPU (see API.md) — it is retired for strategy
-        # unification, and it cannot map onto the Pallas kernel.
+        # unification: it is w dispatches where "kernel" is one.
         t_fused, ids_fused = timed("kernel")
         t_loop, ids_loop = timed("vmap")
         t_map, _ = timed("map")
@@ -152,7 +152,7 @@ def _beam_rows(quick: bool, peaks: Dict[str, float]) -> List[Dict]:
 
         # analytic op counts from the expansions this workload executes:
         # the kernel-strategy prologue (queue drain + descend) feeds the
-        # counting oracle the exact rows the timed call walked
+        # counting walk the exact rows the timed call walked
         qidx = jax.vmap(lambda col: jnp.nonzero(
             col, size=capacity, fill_value=batch)[0])(mask.T)
         qs = q[jnp.clip(qidx, 0, batch - 1)]
@@ -177,7 +177,7 @@ def _beam_rows(quick: bool, peaks: Dict[str, float]) -> List[Dict]:
                        + n_rows * (4.0 * d + 8.0 * efc))
         row = {
             "n_items": n_items, "batch": batch, "ef": ef,
-            "capacity": capacity, "impl": beam_impl(),
+            "capacity": capacity, "impl": "xla-oracle",
             "expansions": e_total,
             "qps_fused": round(batch / t_fused, 1),
             "qps_loop": round(batch / t_loop, 1),

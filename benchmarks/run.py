@@ -15,6 +15,7 @@ from benchmarks import (ablation_partitioner, bench_build,
                         fig8_latency, fig9_comparison, fig10_mips,
                         fig11_scalability, fig12_straggler, fig13_failure,
                         roofline)
+from repro.common.compile_cache import enable_compile_cache
 
 SUITES = {
     "build": bench_build.run,
@@ -33,6 +34,7 @@ SUITES = {
 
 
 def main() -> None:
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--quick", action="store_true",
                     help="small datasets (CI-speed)")

@@ -249,12 +249,21 @@ def _build_shard_payload(task: _ShardPayload) -> Tuple[H.HNSWGraph, float]:
         ef_construction=task.ef_construction, seed=task.seed))
 
 
+def _worker_init() -> None:
+    """Pool initializer: pin the worker's JAX to the CPU before anything
+    can initialise a backend. The worker imports JAX with this module,
+    and an accelerator belongs to one process: the parent holds it."""
+    import jax
+    jax.config.update("jax_platforms", "cpu")
+
+
 def _default_pool(workers: int):
     # spawn, not fork: the parent has a live XLA backend (the planner's
     # device-batched assignment) and forking its threads can deadlock;
     # workers only need numpy, so a clean interpreter is cheap and safe
     return concurrent.futures.ProcessPoolExecutor(
-        max_workers=workers, mp_context=multiprocessing.get_context("spawn"))
+        max_workers=workers, mp_context=multiprocessing.get_context("spawn"),
+        initializer=_worker_init)
 
 
 def build_subgraphs(plan: BuildPlan, *, workers: int = 0,

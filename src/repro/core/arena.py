@@ -269,7 +269,6 @@ def _stack_host(index, quantize=None) -> Dict[str, np.ndarray]:
 def shard_search(arena: ShardArena, mask: jnp.ndarray, queries: jnp.ndarray,
                  *, metric: str, k: int, ef: int, capacity: int,
                  max_iters: int = 400, shard_axis: str = "kernel",
-                 use_kernel: bool = True,
                  tag_words: Optional[jnp.ndarray] = None,
                  filter_words: Optional[jnp.ndarray] = None):
     """Capacity-bounded beam search mapped over the shard axis.
@@ -283,18 +282,16 @@ def shard_search(arena: ShardArena, mask: jnp.ndarray, queries: jnp.ndarray,
       mask: [B, w_arena] bool routing mask aligned with ``arena``.
       queries: [B, d] preprocessed queries.
       shard_axis: "kernel" (default) runs every (shard, slot) pair
-        through ONE fused beam-walk op (``repro.kernels.beam_search``) —
-        the Pallas kernel on TPU, the flattened batched oracle elsewhere.
-        It retires the old backend split ("map" on CPU, "vmap" on TPU)
-        behind one strategy: all w * C rows walk in one loop whose trip
-        count is the global max. "vmap" / "map" keep the per-query
+        through ONE fused beam-walk op (``repro.kernels.beam_search``,
+        a batched XLA walk on every backend). It retires the old
+        backend split ("map" on CPU, "vmap" on TPU) behind one
+        strategy: all w * C rows walk in one loop whose trip count is
+        the global max. "vmap" / "map" keep the per-query
         ``while_loop`` batched / sequentially mapped over the shard axis
         (the roofline's measured baselines; "map"'s per-shard early
         termination keeps it the fastest multi-shard path on CPU — see
         API.md "Fused beam search" for the honest numbers — but it is w
-        sequential dispatches that cannot feed the Pallas kernel).
-      use_kernel: allow the Pallas kernel ("kernel" strategy on TPU).
-        Must be False inside ``shard_map`` — same rule as ``merge_topk``.
+        sequential dispatches instead of one).
       tag_words / filter_words: optional metadata alive-mask
         (``repro.core.filters``): [w, n_pad, 2] i32 item tag words
         aligned with the arena stacking (``PyramidIndex.tags_arena``)
@@ -341,7 +338,6 @@ def shard_search(arena: ShardArena, mask: jnp.ndarray, queries: jnp.ndarray,
             ef=efb, max_iters=max_iters,
             scale=None if scale is None else scale[0],
             zero=None if scale is None else arena.zero[0],
-            use_kernel=use_kernel,
             tag_words=tag_words,
             filter_words=None if fw_pad is None else fw_pad[qidx])
         kk = min(k, scores.shape[-1])
@@ -425,8 +421,7 @@ def _search_scatter_merge(arena: ShardArena, mask: jnp.ndarray,
     qidx, ids, scores = shard_search(
         arena, mask, queries, metric=metric, k=k, ef=ef,
         capacity=capacity, max_iters=max_iters, shard_axis=shard_axis,
-        use_kernel=use_kernel, tag_words=tag_words,
-        filter_words=filter_words)
+        tag_words=tag_words, filter_words=filter_words)
     flat_s, flat_i = scatter_partials(qidx, ids, scores, b)
     top_s, top_i = merge_topk(flat_s, flat_i, k=k, use_kernel=use_kernel)
     return top_i, top_s
@@ -502,8 +497,9 @@ def arena_search(arena: ShardArena, meta: H.HNSWArrays,
         stage (the reference path uses this to guarantee zero drops).
       shard_axis: "kernel" | "vmap" | "map" shard-axis strategy (see
         :func:`shard_search`); defaults to "kernel" — ONE strategy on
-        every backend (the op layer picks Pallas on TPU, the fused
-        oracle elsewhere), retiring the old CPU "map" special case.
+        every backend, retiring the old CPU "map" special case.
+      use_kernel: merge with the ``merge_topk`` Pallas kernel on TPU
+        (False forces its jnp oracle).
       tag_words / filter_words: optional metadata alive-mask (see
         :func:`shard_search`): routing stays filter-blind, the per-shard
         walk emits only alive candidates, the merge fills k from those.

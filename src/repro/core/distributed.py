@@ -32,9 +32,8 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
-
-from repro.common.jax_compat import shard_map
 
 from repro.common.config import PyramidConfig
 from repro.core import filters as F
@@ -322,11 +321,12 @@ def make_pyramid_search_fn(mesh: Mesh, cfg: PyramidConfig, *, k: int,
         qidx, ids, scores = shard_search(
             arena, local_mask, queries, metric=metric, k=k_inner,
             ef=max(ef, k_inner), capacity=capacity, max_iters=max_iters,
-            shard_axis="kernel", use_kernel=False)
+            shard_axis="kernel")
 
         # coordinator merge: gather partials from all shards, then the
-        # same scatter + dedup merge as the fused single-host pipeline
-        # (jnp oracle: the interpret-mode kernel cannot run in shard_map)
+        # same scatter + dedup merge as the fused single-host pipeline,
+        # through the jnp merge: the Pallas merge has not been compiled
+        # inside this shard_map program yet (ROADMAP S7)
         qidx = jax.lax.all_gather(qidx, model_axis, tiled=True)    # [w, C]
         ids = jax.lax.all_gather(ids, model_axis, tiled=True)  # [w, C, k]
         scores = jax.lax.all_gather(scores, model_axis, tiled=True)

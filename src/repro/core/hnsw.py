@@ -535,13 +535,13 @@ def search_one(g: HNSWArrays, q: jnp.ndarray, *, metric: str, k: int,
 
 def search_batch(g: HNSWArrays, queries: jnp.ndarray, *, metric: str,
                  k: int, ef: int, max_iters: int = 400,
-                 max_steps: int = 64, use_kernel: bool = True,
+                 max_steps: int = 64,
                  tag_words: Optional[jnp.ndarray] = None,
                  filter_words: Optional[jnp.ndarray] = None):
     """Batched search through the fused beam-walk op
     (``repro.kernels.beam_search``): greedy upper-layer descent per query
     (cheap, stays in XLA), then ONE fused bottom-layer walk for the whole
-    batch — the Pallas kernel on TPU, the batched jnp oracle elsewhere.
+    batch (one batched XLA ``while_loop`` on every backend).
 
     Bit-identical to ``vmap(search_one)``: the op freezes finished rows
     so the shared loop matches the per-query ``while_loop``, and its
@@ -563,7 +563,6 @@ def search_batch(g: HNSWArrays, queries: jnp.ndarray, *, metric: str,
     scores, nodes = beam_search(
         g.data[None], g.bottom[None], queries[None], entries[None],
         metric=metric, ef=ef, max_iters=max_iters, scale=scale, zero=zero,
-        use_kernel=use_kernel,
         tag_words=None if tag_words is None else tag_words[None],
         filter_words=None if filter_words is None else filter_words[None])
     scores, nodes = scores[0], nodes[0]                # [B, ef']
@@ -583,10 +582,10 @@ def search_batch(g: HNSWArrays, queries: jnp.ndarray, *, metric: str,
 
 
 @partial(jax.jit, static_argnames=("metric", "k", "ef", "max_iters",
-                                   "impl", "use_kernel"))
+                                   "impl"))
 def hnsw_search(g: HNSWArrays, queries: jnp.ndarray, *, metric: str,
                 k: int, ef: int = 100, max_iters: int = 400,
-                impl: str = "fused", use_kernel: bool = True,
+                impl: str = "fused",
                 tag_words: Optional[jnp.ndarray] = None,
                 filter_words: Optional[jnp.ndarray] = None):
     """Batched HNSW search (Alg. 1).
@@ -600,8 +599,6 @@ def hnsw_search(g: HNSWArrays, queries: jnp.ndarray, *, metric: str,
       impl: "fused" (default) runs the whole batch through the fused
         beam-walk op; "loop" keeps the per-query vmapped ``while_loop``
         (the roofline's baseline). Results are identical.
-      use_kernel: allow the Pallas kernel on TPU ("fused" only). Must be
-        False when traced inside ``shard_map`` (e.g. the SPMD router).
       tag_words / filter_words: optional metadata alive-mask — [n, 2]
         i32 item tag words and [B, 2] i32 per-query filter words
         (``repro.core.filters.split_tag_words``); a query whose filter
@@ -612,7 +609,7 @@ def hnsw_search(g: HNSWArrays, queries: jnp.ndarray, *, metric: str,
     """
     if impl == "fused":
         return search_batch(g, queries, metric=metric, k=k, ef=ef,
-                            max_iters=max_iters, use_kernel=use_kernel,
+                            max_iters=max_iters,
                             tag_words=tag_words, filter_words=filter_words)
     if tag_words is None or filter_words is None:
         return jax.vmap(lambda q: search_one(

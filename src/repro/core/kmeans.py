@@ -18,9 +18,9 @@ from typing import Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
-from repro.common.jax_compat import shard_map
 from repro.kernels.topk_distance import topk_similarity
 
 

@@ -35,10 +35,8 @@ def _route_queries(meta: H.HNSWArrays, part_of_center: jnp.ndarray,
                    branching_factor: int, num_shards: int,
                    ef: int = 64) -> Tuple[jnp.ndarray, jnp.ndarray]:
     k = branching_factor
-    # use_kernel=False: routing is traced inside shard_map by the SPMD
-    # path (via ``route_queries.__wrapped__``), where Pallas cannot run
     meta_ids, _ = H.hnsw_search(meta, queries, metric=metric, k=k,
-                                ef=max(ef, k), use_kernel=False)
+                                ef=max(ef, k))
     parts = part_of_center[jnp.clip(meta_ids, 0)]          # [B, K]
     parts = jnp.where(meta_ids >= 0, parts, -1)
     onehot = jax.nn.one_hot(

@@ -1,9 +1,9 @@
-"""Dispatch layer for the fused beam-search op.
+"""The fused beam-search op: the batched XLA walk of ``ref.py`` plus
+the metadata alive-mask.
 
-``beam_search`` picks the Pallas kernel on TPU and the jnp oracle
-everywhere else (same convention as ``merge_topk`` / ``quant_scores``).
-Inside ``shard_map`` callers must force ``use_kernel=False`` — Pallas
-calls cannot be traced there.
+One implementation on every backend. The walk is a ``lax.while_loop``
+over all (graph, slot) rows, so it traces anywhere a jitted function
+does, ``shard_map`` bodies included.
 """
 from __future__ import annotations
 
@@ -12,17 +12,7 @@ from typing import Optional, Tuple
 import jax
 import jax.numpy as jnp
 
-from repro.kernels.beam_search.kernel import beam_search_pallas
 from repro.kernels.beam_search.ref import beam_search_ref
-
-
-def _on_tpu() -> bool:
-    return jax.default_backend() == "tpu"
-
-
-def beam_impl() -> str:
-    """Which implementation ``beam_search`` dispatches to here."""
-    return "pallas-kernel" if _on_tpu() else "xla-oracle"
 
 
 def _apply_filter(scores: jnp.ndarray, nodes: jnp.ndarray,
@@ -31,10 +21,10 @@ def _apply_filter(scores: jnp.ndarray, nodes: jnp.ndarray,
     """Metadata alive-mask on the walk's emitted candidates.
 
     The navigation beam runs unfiltered (masking mid-walk would
-    disconnect the graph); here — identically after the kernel and the
-    oracle — candidates whose tag bitset misses the query's filter are
-    demoted to the (-inf, -1) padding convention, so downstream top-k
-    and merges see them exactly like structural pad slots.
+    disconnect the graph); here, after the walk, candidates whose tag
+    bitset misses the query's filter are demoted to the (-inf, -1)
+    padding convention, so downstream top-k and merges see them exactly
+    like structural pad slots.
 
     tag_words: [S, n, 2] i32 word-split item bitsets; filter_words:
     [S, C, 2] i32 per-slot filters (zero words == no filtering).
@@ -52,8 +42,6 @@ def beam_search(data: jnp.ndarray, bottom: jnp.ndarray,
                 metric: str, ef: int, max_iters: int,
                 scale: Optional[jnp.ndarray] = None,
                 zero: Optional[jnp.ndarray] = None,
-                use_kernel: bool = True, block_q: int = 8,
-                interpret: bool = False,
                 tag_words: Optional[jnp.ndarray] = None,
                 filter_words: Optional[jnp.ndarray] = None
                 ) -> Tuple[jnp.ndarray, jnp.ndarray]:
@@ -66,20 +54,11 @@ def beam_search(data: jnp.ndarray, bottom: jnp.ndarray,
 
     ``tag_words`` ([S, n, 2] i32) + ``filter_words`` ([S, C, 2] i32)
     apply the metadata alive-mask of ``repro.core.filters`` to the
-    emitted candidates — same post-walk masking for kernel and oracle,
-    so filtered results stay implementation-identical.
+    emitted candidates.
     """
-    if not use_kernel or not _on_tpu():
-        out_s, out_i = beam_search_ref(
-            data, bottom, queries, entries, metric=metric, ef=ef,
-            max_iters=max_iters, scale=scale, zero=zero)
-    else:
-        out_s, out_i = beam_search_pallas(
-            data, bottom, queries, entries, metric=metric, ef=ef,
-            max_iters=max_iters, scale=scale, zero=zero, block_q=block_q,
-            interpret=interpret)
-        # kernel pads with the finite NEG_INF sentinel; restore -inf
-        out_s = jnp.where(out_i >= 0, out_s, -jnp.inf)
+    out_s, out_i = beam_search_ref(
+        data, bottom, queries, entries, metric=metric, ef=ef,
+        max_iters=max_iters, scale=scale, zero=zero)
     if tag_words is not None and filter_words is not None:
         out_s, out_i = _apply_filter(out_s, out_i, tag_words, filter_words)
     return out_s, out_i

@@ -1,5 +1,5 @@
-"""References for the fused bottom-layer beam walk: jnp oracle + numpy
-twin.
+"""The fused bottom-layer beam walk: the batched XLA walk that every
+backend serves (``beam_search_ref``) and its numpy twin.
 
 The walk is Alg. 1 Search-Level with search factor ``ef`` on the bottom
 layer, batched over a stack of graphs: every (graph, slot) pair runs the
@@ -11,7 +11,7 @@ position), neighbour scoring through the graph's own distance
 ``top_k``-ordered beam merge — but as ONE batched loop over all
 ``S * C`` rows instead of ``vmap``-of-``while_loop`` per shard.
 
-Semantics shared by every implementation (kernel / jnp / numpy):
+Semantics shared by both implementations (XLA walk / numpy twin):
   * a row expands exactly one beam entry per iteration while it has any
     unexpanded entry and fewer than ``max_iters`` expansions; finished
     rows are frozen (their state never changes), so the batched loop is
@@ -40,7 +40,7 @@ from repro.kernels.quant_distance import quant_scores_np, quant_scores_ref
 def _walk_ref(data: jnp.ndarray, bottom: jnp.ndarray, queries: jnp.ndarray,
               entries: jnp.ndarray, *, metric: str, ef: int, max_iters: int,
               scale: Optional[jnp.ndarray], zero: Optional[jnp.ndarray]):
-    """Shared oracle body; returns (scores, nodes, iters) stacked
+    """Shared walk body; returns (scores, nodes, iters) stacked
     [S, C, ...] with ``iters`` = expansions actually executed per row
     (the roofline's analytic op counts use it)."""
     s, n, d = data.shape
@@ -133,7 +133,7 @@ def beam_search_ref(data: jnp.ndarray, bottom: jnp.ndarray,
                     metric: str, ef: int, max_iters: int,
                     scale: Optional[jnp.ndarray] = None,
                     zero: Optional[jnp.ndarray] = None):
-    """Fused bottom-layer beam walk oracle.
+    """Fused bottom-layer beam walk: one batched ``lax.while_loop``.
 
     Args:
       data: [S, n, d] graph rows — float32, or int8 codes when
@@ -155,7 +155,7 @@ def beam_search_ref(data: jnp.ndarray, bottom: jnp.ndarray,
 
 def beam_search_stats(data, bottom, queries, entries, *, metric: str,
                       ef: int, max_iters: int, scale=None, zero=None):
-    """Oracle walk that also returns per-row expansion counts
+    """The same walk, also returns per-row expansion counts
     [S, C] i32 — ``benchmarks/roofline.py`` derives its analytic
     FLOP/byte counts from the expansions a workload actually executes."""
     return _walk_ref(jnp.asarray(data), jnp.asarray(bottom),
@@ -171,7 +171,7 @@ def beam_search_np(data: np.ndarray, bottom: np.ndarray,
                    zero: Optional[np.ndarray] = None
                    ) -> Tuple[np.ndarray, np.ndarray]:
     """Numpy twin of :func:`beam_search_ref` (per-row Python loop; the
-    independent host-side oracle the kernel tests triangulate against)."""
+    independent host-side oracle the walk's tests compare against)."""
     data = np.asarray(data)
     bottom = np.asarray(bottom)
     queries = np.asarray(queries, np.float32)

@@ -21,8 +21,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.common.jax_compat import CompilerParams as _CompilerParams
-
 NEG_INF = -3.0e38
 
 
@@ -113,7 +111,7 @@ def flash_decode_pallas(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
             pltpu.VMEM((groups, 1), jnp.float32),
             pltpu.VMEM((groups, hd), jnp.float32),
         ],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
     )(posr, qr, kr, vr)

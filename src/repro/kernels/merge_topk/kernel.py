@@ -18,8 +18,7 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-
-from repro.common.jax_compat import CompilerParams as _CompilerParams
+from jax.experimental.pallas import tpu as pltpu
 
 NEG_INF = -3.0e38  # python float so the kernel doesn't capture a traced const
 
@@ -85,7 +84,7 @@ def merge_topk_pallas(scores: jnp.ndarray, ids: jnp.ndarray, *, k: int,
             jax.ShapeDtypeStruct((pb, k), jnp.float32),
             jax.ShapeDtypeStruct((pb, k), jnp.int32),
         ],
-        compiler_params=_CompilerParams(dimension_semantics=("parallel",)),
+        compiler_params=pltpu.CompilerParams(dimension_semantics=("parallel",)),
         interpret=interpret,
     )(sp, ip)
     return out_s[:b], out_i[:b]

@@ -7,8 +7,8 @@ single-host reference path and the SPMD ``shard_map`` program all call it
 everywhere else — this is a production hot path, so off-TPU it should
 run as compiled XLA rather than the interpret-mode kernel (which exists
 for validation and is exercised directly by the kernel tests).
-``use_kernel=False`` forces the oracle, which callers inside
-``shard_map`` need regardless of backend.
+``use_kernel=False`` forces the oracle; the SPMD ``shard_map`` program
+uses it, since the kernel has not been compiled inside that program.
 """
 from __future__ import annotations
 
@@ -43,8 +43,7 @@ def merge_topk(scores: jnp.ndarray, ids: jnp.ndarray, *, k: int,
         convention BEFORE the merge, so filtering can never under-fill
         the k live winners. Applied identically ahead of every
         implementation (kernel / oracle / numpy twin).
-      use_kernel: False forces the jnp oracle (required inside shard_map,
-        where the interpret-mode kernel cannot run).
+      use_kernel: False forces the jnp oracle.
 
     Returns (scores [B, k] f32 descending, ids [B, k] i32), (-inf, -1)
     padded — best-occurrence-wins on duplicate ids, ties broken by input

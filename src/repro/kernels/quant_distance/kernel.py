@@ -20,8 +20,7 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-
-from repro.common.jax_compat import CompilerParams as _CompilerParams
+from jax.experimental.pallas import tpu as pltpu
 
 _EPS = 1e-12  # angular epsilon, identical to repro.core.metrics
 
@@ -88,7 +87,7 @@ def quant_distance_pallas(q: jnp.ndarray, codes: jnp.ndarray,
         ],
         out_specs=pl.BlockSpec((block_q, block_n), lambda i, j: (i, j)),
         out_shape=jax.ShapeDtypeStruct((pb, pn), jnp.float32),
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel")),
         interpret=interpret,
     )(qp, cp, scale.reshape(1, d).astype(jnp.float32),

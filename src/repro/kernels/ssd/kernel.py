@@ -19,8 +19,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.common.jax_compat import CompilerParams as _CompilerParams
-
 
 def _ssd_kernel(x_ref, dt_ref, a_ref, b_ref, c_ref, y_ref, state_ref,
                 st_scratch, *, chunk: int):
@@ -134,7 +132,7 @@ def ssd_pallas(x: jnp.ndarray, dt: jnp.ndarray, a: jnp.ndarray,
             jax.ShapeDtypeStruct((bsz, h, n, p), jnp.float32),
         ],
         scratch_shapes=[pltpu.VMEM((block_h, n, p), jnp.float32)],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(xh, dth, a, bmc, cmc)

@@ -25,8 +25,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.common.jax_compat import CompilerParams as _CompilerParams
-
 NEG_INF = -3.0e38  # python float so the kernel doesn't capture a traced const
 
 
@@ -134,7 +132,7 @@ def topk_similarity_pallas(queries: jnp.ndarray, database: jnp.ndarray, *,
             pltpu.VMEM((block_q, k), jnp.float32),
             pltpu.VMEM((block_q, k), jnp.int32),
         ],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
     )(qp, xp)

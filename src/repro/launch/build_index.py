@@ -26,6 +26,7 @@ from typing import Optional
 
 import numpy as np
 
+from repro.common.compile_cache import enable_compile_cache
 from repro.common.config import PyramidConfig
 from repro.core.meta_index import PyramidIndex
 from repro.data.synthetic import clustered_vectors, norm_spread_vectors
@@ -75,6 +76,7 @@ def load_index(path: str, *, version: Optional[str] = None) -> PyramidIndex:
 
 
 def main() -> None:
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--n", type=int, default=20_000)
     ap.add_argument("--d", type=int, default=32)

@@ -30,6 +30,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.common.compile_cache import enable_compile_cache
 from repro.common.config import INPUT_SHAPES, ArchConfig, InputShape
 from repro.common.registry import get_arch, list_archs
 from repro.launch.mesh import make_production_mesh
@@ -557,6 +558,7 @@ def run_pyramid(multi_pod: bool, out_dir: Optional[str], *,
 
 
 def main() -> None:
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="all",
                     help="arch name or 'all'")

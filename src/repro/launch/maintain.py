@@ -20,12 +20,14 @@ import argparse
 import json
 import time
 
+from repro.common.compile_cache import enable_compile_cache
 from repro.obs import get_logger
 
 log = get_logger(__name__)
 
 
 def main() -> None:
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--store", required=True, help="store root")
     ap.add_argument("--threshold", type=int, default=1,

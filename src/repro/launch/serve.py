@@ -26,6 +26,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.common.compile_cache import enable_compile_cache
 from repro.common.config import PyramidConfig
 from repro.common.registry import get_arch, list_archs
 from repro.models.transformer import grow_cache, init_params
@@ -39,6 +40,7 @@ log = get_logger(__name__)
 
 
 def main(argv=None) -> None:
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="qwen3-1.7b", choices=list_archs())
     ap.add_argument("--batch", type=int, default=2)

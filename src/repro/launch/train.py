@@ -11,6 +11,7 @@ import time
 
 import jax.numpy as jnp
 
+from repro.common.compile_cache import enable_compile_cache
 from repro.common.registry import get_arch, list_archs
 from repro.data.synthetic import SyntheticLM
 from repro.launch.mesh import make_local_mesh, make_production_mesh
@@ -23,6 +24,7 @@ log = get_logger(__name__)
 
 
 def main() -> None:
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="qwen3-1.7b", choices=list_archs())
     ap.add_argument("--reduced", action="store_true",

@@ -265,8 +265,8 @@ class Executor(threading.Thread):
         the coordinator's exact rerank).
 
         ``hnsw_search`` defaults to the fused beam-walk op
-        (``repro.kernels.beam_search`` — Pallas kernel on TPU, batched
-        oracle elsewhere), so every executor batch, including
+        (``repro.kernels.beam_search``, one batched XLA walk on every
+        backend), so every executor batch, including
         ``StreamEngine``'s per-decode-step lookups, rides it.
 
         Filtered requests (``r.filter_tags != 0``) search at their
@@ -950,7 +950,8 @@ class ServingEngine:
         redispatched = int(self._m_redispatched.value)
         execs = {
             name: {"shard": ex.shard_id, "alive": ex.alive,
-                   "processed": ex.processed, "cpu_share": ex.cpu_share}
+                   "warmed": ex.warmed, "processed": ex.processed,
+                   "cpu_share": ex.cpu_share}
             for name, ex in sorted(list(self.executors.items()))}
         return {
             "num_shards": self.w,

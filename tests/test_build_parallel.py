@@ -10,6 +10,7 @@ import pytest
 
 from repro.build import (BuildError, build_pyramid_index_parallel,
                          build_subgraphs, plan_build)
+from repro.build.planner import _default_pool
 from repro.common.config import PyramidConfig
 from repro.core.distributed import search_single_host
 from repro.data.synthetic import clustered_vectors, query_set
@@ -161,3 +162,18 @@ def test_workers_default_caps_at_shards(data):
     idx = build_pyramid_index_parallel(data, CFG, workers=None)
     assert idx.num_shards == CFG.num_shards
     assert idx.build_stats["build_workers"] <= CFG.num_shards
+
+
+def test_pool_workers_pin_jax_to_cpu(monkeypatch):
+    """A build worker must never claim the accelerator its parent
+    holds: the pool's initializer pins the worker's JAX to the CPU
+    before any task runs, whatever the inherited environment says."""
+    monkeypatch.delenv("JAX_PLATFORMS", raising=False)
+    pool = _default_pool(1)
+    try:
+        got = pool.submit(
+            eval, "__import__('jax').config.jax_platforms").result(
+                timeout=120)
+    finally:
+        pool.shutdown()
+    assert got == "cpu"

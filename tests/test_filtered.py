@@ -1,4 +1,4 @@
-"""Metadata-filtered kNN: kernel/oracle/numpy parity, the sel-1.0
+"""Metadata-filtered kNN: XLA walk/numpy twin parity, the sel-1.0
 bit-identity contract, empty filters, tag persistence through the delta
 log + compaction, the engine's filtered serving path, and the merge
 alive-mask (tombstones must never crowd live results out of k)."""
@@ -15,9 +15,7 @@ from repro.core.distributed import search_single_host
 from repro.core.meta_index import build_pyramid_index
 from repro.core.updates import add_items, remove_items, set_item_tags
 from repro.data.synthetic import query_set
-from repro.kernels.beam_search.kernel import beam_search_pallas
-from repro.kernels.beam_search.ops import _apply_filter
-from repro.kernels.beam_search.ref import beam_search_ref
+from repro.kernels.beam_search import beam_search, beam_search_np
 from repro.kernels.merge_topk.ref import merge_topk_np
 from repro.serving.engine import ServingEngine
 from repro.store import IndexStore
@@ -88,8 +86,8 @@ def test_sel1_bit_identical_graph_paths(metric, impl):
 
 
 def test_filtered_kernel_oracle_parity():
-    """Non-trivial filters: the Pallas kernel (interpret) and the jnp
-    oracle agree exactly after the shared alive-mask, and every
+    """Non-trivial filters: the XLA walk with its on-device alive-mask
+    agrees exactly with the numpy twin masked on the host, and every
     surviving candidate actually matches its slot's filter."""
     rng = np.random.default_rng(7)
     s, n, d, c, m0 = 2, 64, 6, 8, 6
@@ -103,19 +101,18 @@ def test_filtered_kernel_oracle_parity():
     tw = jnp.asarray(F.split_tag_words(tags))
     fw = jnp.asarray(F.filter_words(filters))
     kw = dict(metric="l2", ef=16, max_iters=100)
-    s_k, n_k = beam_search_pallas(
+    s_r, n_r = beam_search(
         jnp.asarray(x), jnp.asarray(bottom), jnp.asarray(queries),
-        jnp.asarray(entries), interpret=True, **kw)
-    s_k = jnp.where(n_k >= 0, s_k, -jnp.inf)
-    s_k, n_k = _apply_filter(s_k, n_k, tw, fw)
-    s_r, n_r = beam_search_ref(
-        jnp.asarray(x), jnp.asarray(bottom), jnp.asarray(queries),
-        jnp.asarray(entries), **kw)
-    s_r, n_r = _apply_filter(s_r, n_r, tw, fw)
-    np.testing.assert_array_equal(np.asarray(n_k), np.asarray(n_r))
-    np.testing.assert_allclose(np.asarray(s_k), np.asarray(s_r),
-                               rtol=1e-5, atol=1e-5)
-    nodes = np.asarray(n_k)
+        jnp.asarray(entries), tag_words=tw, filter_words=fw, **kw)
+    s_n, n_n = beam_search_np(x, bottom, queries, entries, **kw)
+    cand_tags = tags[np.arange(s)[:, None, None], np.clip(n_n, 0, None)]
+    alive = (n_n >= 0) & F.alive_np(cand_tags, filters[:, :, None])
+    s_n = np.where(alive, s_n, -np.inf)
+    n_n = np.where(alive, n_n, -1)
+    np.testing.assert_array_equal(np.asarray(n_r), n_n)
+    np.testing.assert_allclose(np.asarray(s_r), s_n, rtol=1e-5,
+                               atol=1e-5)
+    nodes = np.asarray(n_r)
     for si in range(s):
         for ci in range(c):
             for v in nodes[si, ci]:
