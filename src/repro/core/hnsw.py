@@ -537,7 +537,8 @@ def search_batch(g: HNSWArrays, queries: jnp.ndarray, *, metric: str,
                  k: int, ef: int, max_iters: int = 400,
                  max_steps: int = 64,
                  tag_words: Optional[jnp.ndarray] = None,
-                 filter_words: Optional[jnp.ndarray] = None):
+                 filter_words: Optional[jnp.ndarray] = None,
+                 stats: bool = False):
     """Batched search through the fused beam-walk op
     (``repro.kernels.beam_search``): greedy upper-layer descent per query
     (cheap, stays in XLA), then ONE fused bottom-layer walk for the whole
@@ -553,6 +554,9 @@ def search_batch(g: HNSWArrays, queries: jnp.ndarray, *, metric: str,
     filter per query) route the metadata alive-mask through the fused
     op — candidates whose bitset misses the filter come back (-inf, -1)
     before the top-k here (same contract as ``search_one``).
+
+    ``stats=True`` also returns each query's bottom-layer expansion
+    count [B] i32.
     """
     ef = max(ef, k)
     entries = jax.vmap(
@@ -560,11 +564,12 @@ def search_batch(g: HNSWArrays, queries: jnp.ndarray, *, metric: str,
             queries)
     scale = getattr(g, "scale", None)
     zero = getattr(g, "zero", None)
-    scores, nodes = beam_search(
+    scores, nodes, iters = beam_search(
         g.data[None], g.bottom[None], queries[None], entries[None],
         metric=metric, ef=ef, max_iters=max_iters, scale=scale, zero=zero,
         tag_words=None if tag_words is None else tag_words[None],
-        filter_words=None if filter_words is None else filter_words[None])
+        filter_words=None if filter_words is None else filter_words[None],
+        stats=True)
     scores, nodes = scores[0], nodes[0]                # [B, ef']
     kk = min(k, scores.shape[1])
     top_scores, idx = jax.lax.top_k(scores, kk)
@@ -578,16 +583,19 @@ def search_batch(g: HNSWArrays, queries: jnp.ndarray, *, metric: str,
         top_scores = jnp.concatenate(
             [top_scores, jnp.full((b, pad), -jnp.inf, jnp.float32)],
             axis=1)
+    if stats:
+        return ext, top_scores, iters[0]
     return ext, top_scores
 
 
 @partial(jax.jit, static_argnames=("metric", "k", "ef", "max_iters",
-                                   "impl"))
+                                   "impl", "stats"))
 def hnsw_search(g: HNSWArrays, queries: jnp.ndarray, *, metric: str,
                 k: int, ef: int = 100, max_iters: int = 400,
                 impl: str = "fused",
                 tag_words: Optional[jnp.ndarray] = None,
-                filter_words: Optional[jnp.ndarray] = None):
+                filter_words: Optional[jnp.ndarray] = None,
+                stats: bool = False):
     """Batched HNSW search (Alg. 1).
 
     Args:
@@ -603,14 +611,21 @@ def hnsw_search(g: HNSWArrays, queries: jnp.ndarray, *, metric: str,
         i32 item tag words and [B, 2] i32 per-query filter words
         (``repro.core.filters.split_tag_words``); a query whose filter
         words are zero runs unfiltered.
+      stats: also return each query's bottom-layer expansion count
+        ([B] i32; their maximum is the walk loop's trip count). Fused
+        walk only.
 
     Returns:
-      (ids [B, k] int32 external ids (-1 pad), scores [B, k] f32) best-first.
+      (ids [B, k] int32 external ids (-1 pad), scores [B, k] f32) best-first,
+      then the expansion counts when ``stats``.
     """
     if impl == "fused":
         return search_batch(g, queries, metric=metric, k=k, ef=ef,
                             max_iters=max_iters,
-                            tag_words=tag_words, filter_words=filter_words)
+                            tag_words=tag_words, filter_words=filter_words,
+                            stats=stats)
+    if stats:
+        raise ValueError("stats=True needs impl='fused'")
     if tag_words is None or filter_words is None:
         return jax.vmap(lambda q: search_one(
             g, q, metric=metric, k=k, ef=ef, max_iters=max_iters))(queries)

@@ -12,7 +12,7 @@ from typing import Optional, Tuple
 import jax
 import jax.numpy as jnp
 
-from repro.kernels.beam_search.ref import beam_search_ref
+from repro.kernels.beam_search.ref import beam_search_stats
 
 
 def _apply_filter(scores: jnp.ndarray, nodes: jnp.ndarray,
@@ -43,8 +43,8 @@ def beam_search(data: jnp.ndarray, bottom: jnp.ndarray,
                 scale: Optional[jnp.ndarray] = None,
                 zero: Optional[jnp.ndarray] = None,
                 tag_words: Optional[jnp.ndarray] = None,
-                filter_words: Optional[jnp.ndarray] = None
-                ) -> Tuple[jnp.ndarray, jnp.ndarray]:
+                filter_words: Optional[jnp.ndarray] = None,
+                stats: bool = False):
     """Fused bottom-layer beam walk over a stack of graphs.
 
     See ``ref.beam_search_ref`` for the shared shape/semantics contract:
@@ -55,10 +55,15 @@ def beam_search(data: jnp.ndarray, bottom: jnp.ndarray,
     ``tag_words`` ([S, n, 2] i32) + ``filter_words`` ([S, C, 2] i32)
     apply the metadata alive-mask of ``repro.core.filters`` to the
     emitted candidates.
+
+    ``stats=True`` also returns each row's expansion count [S, C] i32
+    (the loop's trip count is their maximum).
     """
-    out_s, out_i = beam_search_ref(
+    out_s, out_i, iters = beam_search_stats(
         data, bottom, queries, entries, metric=metric, ef=ef,
         max_iters=max_iters, scale=scale, zero=zero)
     if tag_words is not None and filter_words is not None:
         out_s, out_i = _apply_filter(out_s, out_i, tag_words, filter_words)
+    if stats:
+        return out_s, out_i, iters
     return out_s, out_i

@@ -156,7 +156,8 @@ def beam_search_ref(data: jnp.ndarray, bottom: jnp.ndarray,
 def beam_search_stats(data, bottom, queries, entries, *, metric: str,
                       ef: int, max_iters: int, scale=None, zero=None):
     """The same walk, also returns per-row expansion counts
-    [S, C] i32 — ``benchmarks/roofline.py`` derives its analytic
+    [S, C] i32 — the served op (``ops.beam_search(stats=True)``) reports
+    them per launch, and ``benchmarks/roofline.py`` derives its analytic
     FLOP/byte counts from the expansions a workload actually executes."""
     return _walk_ref(jnp.asarray(data), jnp.asarray(bottom),
                      jnp.asarray(queries), jnp.asarray(entries),

@@ -21,6 +21,20 @@ a :class:`repro.serving.faults.FaultSchedule` replay with a scripted
 clock the span set and its parent/child edges are reproducible (span
 ids come from one atomic counter; timestamps come from the clock).
 
+Profiler: while a tracer is enabled and a profiler session is on,
+every ``tracer.span(...)`` also opens a ``jax.profiler.TraceAnnotation``
+of the same name (a span entered before the session starts has none),
+carrying ``span_id``, ``parent_id`` and the span's attrs (``set`` ones
+too) as metadata, so any profile taken while serving
+(``jax.profiler.trace``, the benchmark's traced slice) holds the
+program's spans on the device trace's clock, on the threads that ran
+them. The annotation opens
+before the span's ``t0`` and closes after its ``t1``, so span durations
+exclude its cost. Spans opened with ``start()``/``end()`` (which may end
+on another thread) are not annotated. The spans keep the tracer's own
+clock; to map it onto a profile, match annotated events to spans by
+``span_id`` and take the median of ``t0`` minus the event's start.
+
 Export: :meth:`Tracer.chrome_trace` emits Chrome ``trace_event`` JSON
 (the ``{"traceEvents": [...]}`` object form) loadable by Perfetto /
 ``chrome://tracing`` — complete (``ph: "X"``) events carry the span id
@@ -41,6 +55,10 @@ import json
 import threading
 import time
 from typing import Dict, List, Optional
+
+from jax.profiler import TraceAnnotation
+
+_profiling = TraceAnnotation.is_enabled   # is a profiler session on?
 
 
 class Span:
@@ -63,6 +81,9 @@ class Span:
     @property
     def duration(self) -> float:
         return (self.t1 if self.t1 is not None else self.t0) - self.t0
+
+    def set(self, **attrs) -> None:
+        self.attrs.update(attrs)
 
     def __repr__(self) -> str:  # pragma: no cover - debug aid
         return (f"Span({self.name!r}, id={self.span_id}, "
@@ -96,14 +117,18 @@ _NULL_SPAN = _NullSpan()
 class _SpanCtx:
     """Context-manager handle pairing a live Span with its tracer (and
     the thread-local parent stack, resolved once at creation — the
-    enter/exit fast path must not repay the thread-local lookup)."""
+    enter/exit fast path must not repay the thread-local lookup). On
+    enter it opens the span's profiler annotation if a profiler session
+    is on, then stamps ``t0``; on exit it stamps ``t1``, then closes the
+    annotation."""
 
-    __slots__ = ("tracer", "span", "stack")
+    __slots__ = ("tracer", "span", "stack", "ann")
 
     def __init__(self, tracer: "Tracer", span: Span, stack: list):
         self.tracer = tracer
         self.span = span
         self.stack = stack
+        self.ann = None
 
     @property
     def span_id(self) -> int:
@@ -111,9 +136,19 @@ class _SpanCtx:
 
     def set(self, **attrs) -> None:
         self.span.attrs.update(attrs)
+        if self.ann is not None:
+            self.ann.set_metadata(**attrs)
 
     def __enter__(self) -> "_SpanCtx":
-        self.stack.append(self.span)
+        span = self.span
+        if _profiling():
+            meta = {"span_id": span.span_id}
+            if span.parent_id is not None:
+                meta["parent_id"] = span.parent_id
+            self.ann = TraceAnnotation(span.name, **meta, **span.attrs)
+            self.ann.__enter__()
+        span.t0 = self.tracer.clock()
+        self.stack.append(span)
         return self
 
     def __exit__(self, *exc) -> bool:
@@ -123,6 +158,8 @@ class _SpanCtx:
             stack.pop()
         span.t1 = self.tracer.clock()
         self.tracer._spans.append(span)
+        if self.ann is not None:
+            self.ann.__exit__(*exc)
         return False
 
 
@@ -151,19 +188,17 @@ class Tracer:
 
     # -- recording ---------------------------------------------------------
 
-    def _stack(self) -> list:
-        local = self._local
-        stack = getattr(local, "stack", None)
-        if stack is None:
-            stack = local.stack = []
-        return stack
+    def _thread(self) -> tuple:
+        """This thread's (open-span stack, thread name), made once."""
+        try:
+            return self._local.state
+        except AttributeError:
+            state = self._local.state = (
+                [], threading.current_thread().name)
+            return state
 
-    def _tname(self) -> str:
-        local = self._local
-        tname = getattr(local, "tname", None)
-        if tname is None:
-            tname = local.tname = threading.current_thread().name
-        return tname
+    def _stack(self) -> list:
+        return self._thread()[0]
 
     def current(self) -> Optional[Span]:
         """The innermost open ``span()`` on this thread, if any."""
@@ -178,12 +213,11 @@ class Tracer:
         thread's innermost open span."""
         if not self.enabled:
             return _NULL_SPAN
-        if parent is None:
-            stack = self._stack()
-            if stack:
-                parent = stack[-1].span_id
-        return Span(name, next(self._ids), parent, self.clock(),
-                    self._tname(), attrs)
+        stack, tname = self._thread()
+        if parent is None and stack:
+            parent = stack[-1].span_id
+        return Span(name, next(self._ids), parent, self.clock(), tname,
+                    attrs)
 
     def end(self, span) -> None:
         if span is _NULL_SPAN or not self.enabled:
@@ -192,15 +226,16 @@ class Tracer:
         self._spans.append(span)
 
     def span(self, name: str, parent: Optional[int] = None, **attrs):
-        """Context manager form; nests via the thread-local stack."""
+        """Context manager form; nests via the thread-local stack and
+        mirrors itself into the profiler (module docstring). ``t0`` is
+        stamped on enter."""
         if not self.enabled:
             return _NULL_SPAN
-        stack = self._stack()
+        stack, tname = self._thread()
         if parent is None and stack:
             parent = stack[-1].span_id
-        span = Span(name, next(self._ids), parent, self.clock(),
-                    self._tname(), attrs)
-        return _SpanCtx(self, span, stack)
+        return _SpanCtx(self, Span(name, next(self._ids), parent, 0.0,
+                                   tname, attrs), stack)
 
     def instant(self, name: str, parent: Optional[int] = None,
                 **attrs) -> None:

@@ -88,6 +88,7 @@ class QueryRequest:
     span_id: Optional[int] = None   # the query's root trace span, if any
     filter_tags: int = 0      # metadata filter bitset (0 = unfiltered)
     fetch_k: int = 0          # selectivity-inflated per-shard fetch width
+    queued: object = None     # this copy's open executor.queued span
 
 
 @dataclasses.dataclass
@@ -289,13 +290,20 @@ class Executor(threading.Thread):
             fp[: len(batch)] = filt   # pad rows: word 0 = unfiltered
             filt_kw = dict(tag_words=self.tag_words,
                            filter_words=jnp.asarray(F.filter_words(fp)))
+        # the span ends with the results on the host; the walk's
+        # per-row expansion counts come back from the same compiled
+        # program traced or not, and are copied only when traced
         with self.tracer.span("kernel.beam_walk", shard=self.shard_id,
-                              k=k, batch=len(batch)):
-            ids, scores = H.hnsw_search(
+                              k=k, batch=len(batch)) as walk:
+            ids, scores, iters = H.hnsw_search(
                 self.graph, jnp.asarray(vecs), metric=self.metric,
-                k=k, ef=max(self.ef, k), **filt_kw)
-        ids = np.asarray(ids)
-        scores = np.asarray(scores)
+                k=k, ef=max(self.ef, k), stats=True, **filt_kw)
+            ids = np.asarray(ids)
+            scores = np.asarray(scores)
+            if self.tracer.enabled:
+                iters = np.asarray(iters)
+                walk.set(expansions=int(iters[: len(batch)].sum()),
+                         trips=int(iters.max()))
         return [(ids[i, : max(r.k, r.fetch_k) * self.k_factor],
                  scores[i, : max(r.k, r.fetch_k) * self.k_factor])
                 for i, r in enumerate(batch)]
@@ -354,14 +362,19 @@ class Executor(threading.Thread):
                         except queue.Empty:
                             break   # linger window expired, still empty
                 self._set_inflight(batch)
+                queued = ([r.queued for r in batch if r.queued is not None]
+                          if self.tracer.enabled else ())
+                for sp in queued:
+                    self.tracer.end(sp)
                 if self.fault_tick is not None:
                     self.fault_tick(self.name)   # drain boundary: a kill
                 if not self.alive:      # event lands mid-batch, items
                     return              # in hand (finally re-enqueues)
                 with self.tracer.span(
                         "executor.batch", executor=self.name,
-                        shard=self.shard_id, n=len(batch),
-                        queries=[r.query_id for r in batch]):
+                        shard=self.shard_id, n=len(batch)) as bspan:
+                    for sp in queued:
+                        sp.set(batch_span=bspan.span_id)
                     t0 = time.monotonic()
                     # a thread blocked in XLA cannot heartbeat: flag the
                     # window so the monitor judges it on search_grace_s,
@@ -403,8 +416,8 @@ class Executor(threading.Thread):
             else:   # engine-less executor (unit tests): raw requeue
                 now = time.monotonic()
                 for r in self.take_inflight():
-                    self.topic.put(
-                        dataclasses.replace(r, submitted_at=now))
+                    self.topic.put(dataclasses.replace(
+                        r, submitted_at=now, queued=None))
 
 
 class Monitor(threading.Thread):
@@ -1062,13 +1075,15 @@ class ServingEngine:
             for f in np.unique(filt[filt != 0]):
                 sel = F.selectivity_np(self._tags_host, int(f))
                 fetch[filt == f] = k * F.inflation(sel)
+        # the route span ends once the mask is on the host
         with self.tracer.span("coordinator.route", n=int(q.shape[0]),
                               branching_factor=kb):
             mask, _ = route_queries(
                 self.meta_arrays, self.part_of_center, jnp.asarray(q),
                 metric=self.metric, branching_factor=kb,
                 num_shards=self.w, ef=_ROUTING_EF)
-        mask = np.asarray(mask)
+            mask = np.asarray(mask)
+        traced = self.tracer.enabled
         futures = []
         now = time.monotonic()
         with self._lock:
@@ -1098,8 +1113,9 @@ class ServingEngine:
                 # the query's root span stays open until the future
                 # resolves (merge, expiry, or shutdown); every dispatch,
                 # hedge, merge, and rerank span hangs off it
-                qspan = self.tracer.start("query", qid=qid, k=k,
-                                          shards=list(topics))
+                qspan = self.tracer.start(
+                    "query", qid=qid, k=k,
+                    shards=list(topics) if traced else None)
                 req = QueryRequest(qid, q[i], k, len(topics), now,
                                    span_id=qspan.span_id,
                                    filter_tags=int(filt[i]),
@@ -1111,8 +1127,7 @@ class ServingEngine:
                 for s in topics:
                     self.tracer.instant("dispatch", parent=qspan.span_id,
                                         qid=qid, shard=s, attempt=0)
-                    self.topics[s].put(
-                        dataclasses.replace(req, shard=s))
+                    self._enqueue(dataclasses.replace(req, shard=s))
                 futures.append(fut)
         return futures
 
@@ -1146,7 +1161,7 @@ class ServingEngine:
             self.tracer.instant("recovery.redispatch", parent=r.span_id,
                                 qid=r.query_id, shard=r.shard,
                                 attempt=r.attempt, executor=ex.name)
-            self.topics[r.shard].put(r)
+            self._enqueue(r)
         return len(requeue)
 
     def _hedge_deadline(self, shard: int) -> float:
@@ -1200,7 +1215,17 @@ class ServingEngine:
             self.tracer.instant("hedge.redispatch", parent=r.span_id,
                                 qid=r.query_id, shard=r.shard,
                                 attempt=r.attempt)
-            self.topics[r.shard].put(r)
+            self._enqueue(r)
+
+    def _enqueue(self, r: QueryRequest) -> None:
+        """Put one shard copy (primary, hedge or recovery) on its topic.
+        While tracing, its ``executor.queued`` span opens here, a child
+        of the query's root; the executor that drains it ends it."""
+        r.queued = (self.tracer.start(
+            "executor.queued", parent=r.span_id, qid=r.query_id,
+            shard=r.shard, attempt=r.attempt)
+            if self.tracer.enabled else None)
+        self.topics[r.shard].put(r)
 
     # -- merge -------------------------------------------------------------
 

@@ -3,7 +3,9 @@ registry rendering/snapshot semantics, tracer causality + Chrome
 export, the stats HTTP server, the LatencyTracker edge cases the
 hedging machinery depends on, and compactor counter parity.
 """
+import glob
 import json
+import os
 import threading
 import urllib.request
 
@@ -209,6 +211,58 @@ def test_disabled_tracer_records_nothing_until_enabled():
     with tr.span("b"):
         pass
     assert [s.name for s in tr.snapshot()] == ["b"]
+
+
+def _host_events(log_dir):
+    """``{name: [stats dict, ...]}`` of the profile's host-plane events."""
+    from jax.profiler import ProfileData
+    (path,) = glob.glob(os.path.join(str(log_dir), "plugins", "profile",
+                                     "*", "*.xplane.pb"))
+    events = {}
+    for plane in ProfileData.from_file(path).planes:
+        if plane.name != "/host:CPU":
+            continue
+        for line in plane.lines:
+            for ev in line.events:
+                events.setdefault(ev.name, []).append(dict(ev.stats))
+    return events
+
+
+def test_scoped_spans_reach_the_profiler_host_plane(tmp_path):
+    import jax
+    tr, off = Tracer(), Tracer(enabled=False)
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        with tr.span("coordinator.route", n=2) as route:
+            with tr.span("kernel.beam_walk", qid=7) as walk:
+                walk.set(trips=3)
+        with off.span("off.span"):
+            pass
+        with NULL_TRACER.span("null.span"):
+            pass
+        root = tr.start("query", qid=7)     # start()/end(): no annotation
+        tr.end(root)
+    finally:
+        jax.profiler.stop_trace()
+    events = _host_events(tmp_path)
+    (w,) = events["kernel.beam_walk"]
+    assert w["span_id"] == walk.span_id
+    assert w["parent_id"] == route.span_id
+    assert (w["qid"], w["trips"]) == (7, 3)
+    (r,) = events["coordinator.route"]
+    assert (r["span_id"], r["n"]) == (route.span_id, 2)
+    assert "parent_id" not in r
+    for name in ("off.span", "null.span", "query"):
+        assert name not in events
+    assert off.snapshot() == []
+    by_name = {s.name: s for s in tr.snapshot()}
+    assert set(by_name) == {"coordinator.route", "kernel.beam_walk",
+                            "query"}
+    q = by_name["query"]
+    assert q.t1 >= q.t0 and q.attrs == {"qid": 7}
+    assert by_name["kernel.beam_walk"].attrs == {"qid": 7, "trips": 3}
+    r_span = by_name["coordinator.route"]
+    assert r_span.t0 <= by_name["kernel.beam_walk"].t0 <= r_span.t1
 
 
 # ---------------------------------------------------------------------------
